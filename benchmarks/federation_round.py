@@ -49,6 +49,7 @@ import json
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.federation import (Federation, FederationConfig,
                                    SequentialFederation)
@@ -552,4 +553,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -50,6 +50,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.models import transformer as T
 from repro.roofline.analysis import decode_roofline
@@ -445,4 +446,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -8,6 +8,7 @@ the paper's 'geometric Rosetta stone' at work.
 """
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import cka as C
 from repro.core.federation import Federation, FederationConfig
@@ -53,4 +54,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

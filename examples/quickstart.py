@@ -12,6 +12,7 @@ reference loop the engine is equivalence-tested against.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.federation import (Federation, FederationConfig,
                                    SequentialFederation)
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

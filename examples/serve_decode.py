@@ -62,6 +62,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.models import transformer as T
 from repro.serve import (ServeConfig, ServeEngine, poisson_requests,
@@ -233,4 +234,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -78,6 +78,7 @@ kill-and-resume proof.
 import argparse
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 
@@ -112,4 +113,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -230,10 +230,13 @@ class RoundEngine:
         if ecfg.gram_backend not in ("auto", "reference", "pallas"):
             raise ValueError(f"unknown gram_backend {ecfg.gram_backend!r}; "
                              f"expected auto | reference | pallas")
-        self._gram_backend = ecfg.gram_backend
-        if self._gram_backend == "auto":
-            self._gram_backend = ("pallas" if jax.default_backend() == "tpu"
-                                  else "reference")
+        # the resolved backend and whether the kernel runs interpreted are
+        # public, so a chip check can see a silent fallback
+        on_tpu = jax.default_backend() == "tpu"
+        self.gram_backend = ecfg.gram_backend
+        if self.gram_backend == "auto":
+            self.gram_backend = "pallas" if on_tpu else "reference"
+        self.gram_interpret = self.gram_backend == "pallas" and not on_tpu
         # canonical node ids per bucket (row order) and the row offset of
         # each bucket — the participation sampler's group layout
         groups, offs, off = [], [], 0
@@ -274,11 +277,10 @@ class RoundEngine:
         """(K, Ba, D) -> (K, Ba, Ba) anchor Grams, dispatched by backend:
         the MXU-tiled Pallas kernel on TPU (interpret mode elsewhere, so
         the dispatch stays CPU-testable), the jnp reference otherwise."""
-        if self._gram_backend == "pallas":
+        if self.gram_backend == "pallas":
             from repro.kernels.gram import cosine_gram_pallas
-            fn = functools.partial(
-                cosine_gram_pallas,
-                interpret=(jax.default_backend() != "tpu"))
+            fn = functools.partial(cosine_gram_pallas,
+                                   interpret=self.gram_interpret)
             return jax.vmap(fn)(pooled_a)
         return jax.vmap(cka_mod.cosine_gram)(pooled_a)
 
@@ -1232,15 +1234,8 @@ class RoundEngine:
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax <= 0.4.x exposes it under
-    jax.experimental (with ``check_rep``); newer releases move it to
-    ``jax.shard_map`` and rename/ drop that kwarg."""
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-    except ImportError:                                   # jax >= 0.7
-        sm = jax.shard_map
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with the varying-manual-axes check off: the round
+    bodies mix per-shard and replicated values that the check cannot
+    type."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

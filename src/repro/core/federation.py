@@ -69,6 +69,7 @@ default.  Architecture:
 from __future__ import annotations
 
 import functools
+import zlib
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -194,7 +195,7 @@ class SequentialFederation:
             for m, (raw, _) in anchors_raw.items():
                 noisy = raw + fed.synthetic_anchor_noise * \
                     jax.random.normal(jax.random.fold_in(
-                        kn, hash(m) % (2 ** 31)), raw.shape)
+                        kn, zlib.crc32(m.encode()) % (2 ** 31)), raw.shape)
                 self.synthetic_anchor_tokens[m] = self.tokenizers[m](noisy)
 
         # ---- global model (the paper's VLM-initialised homogeneous

@@ -15,6 +15,7 @@ aggregation).
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
@@ -48,7 +49,7 @@ class SyntheticMultimodal:
 
     def _modality_map(self, modality: str):
         _, k, *_ = self._keys()
-        km = jax.random.fold_in(k, hash(modality) % (2 ** 31))
+        km = jax.random.fold_in(k, zlib.crc32(modality.encode()) % (2 ** 31))
         k1, k2 = jax.random.split(km)
         w = jax.random.normal(k1, (self.d_latent, self.d_raw)) \
             * self.d_latent ** -0.5

@@ -13,6 +13,7 @@ shared in the federation").
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass
 
 import jax
@@ -31,7 +32,8 @@ class FrozenTokenizer:
     seed: int = 0
 
     def _weights(self):
-        k = jax.random.PRNGKey(hash((self.modality, self.seed)) % (2 ** 31))
+        k = jax.random.PRNGKey(zlib.crc32(
+            f"{self.modality}/{self.seed}".encode()) % (2 ** 31))
         k1, k2, k3 = jax.random.split(k, 3)
         w1 = jax.random.normal(k1, (self.d_raw, self.n_tokens, self.d_out)) \
             * self.d_raw ** -0.5

@@ -4,13 +4,13 @@ Decode queries are one token per slot, so the flash kernel's (bq, dh)
 query panel degenerates to a single sublane at bq=1 — almost the whole
 MXU tile is padding.  This kernel instead packs the ``rep = H // KV``
 query heads that share a KV head into the SUBLANE dimension: the grid is
-``(S slots, KV heads, nkv KV blocks)`` and each cell contracts a
-(rep, dh) query panel against a (bkv, dh) KV panel, so the score tile is
-(rep, bkv) and no panel row is wasted on sequence padding.  The GQA
-grouping itself is the same zero-copy ``index_map`` trick as
-``flash_attention.py``: q is viewed as (S, KV, rep, dh) and the KV
-BlockSpec indexes head ``g`` of the un-repeated (S, C, KV, dh) pool — K/V
-are never materially repeated in HBM.
+``(S slots, nkv KV blocks)`` and each cell contracts, for every KV head
+``g``, a (rep, dh) query panel against the (bkv, dh) panel of head ``g``,
+so the score tile is (rep, bkv) and no panel row is wasted on sequence
+padding.  K/V are never repeated in HBM: q is viewed as (S, KV, rep, dh)
+and each grid cell DMAs one (bkv, KV, dh) block of the un-repeated
+(S, C, KV, dh) pool — every KV head at once, since a block's last two
+dimensions must be whole (or (8, 128)-aligned) for the TPU's tiling.
 
 Masking is positional, matching the serving cache layout exactly: every
 pool entry carries its absolute position (``kv_pos``; empty / padded
@@ -21,9 +21,11 @@ slots, AND ring-buffer sliding windows:
     ok = (kv_pos <= q_pos) & (q_pos - kv_pos < window)
 
 with ``window = cache_len`` for non-windowed caches (a linear buffer
-never holds a position older than cache_len).  The KV axis is innermost
-so the online-softmax running state (m, l, acc) lives in VMEM scratch
-across sequential KV steps, exactly like the flash kernel.
+never holds a position older than cache_len).  ``q_pos`` is scalar-
+prefetched into SMEM whole; ``kv_pos`` is viewed as (S, 1, C) so its
+(1, bkv) block is lane-major.  The KV axis is innermost so the
+online-softmax running state (m, l, acc) lives in VMEM scratch across
+sequential KV steps, exactly like the flash kernel.
 """
 from __future__ import annotations
 
@@ -40,8 +42,8 @@ NEG_INF = -1e30
 
 def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float, window: int,
-                   nkv: int):
-    ki = pl.program_id(2)
+                   nkv: int, n_kv: int):
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -49,29 +51,33 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale        # (rep, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (bkv, dh)
-    s = q @ k.T                                        # (rep, bkv)
-    qp = qpos_ref[0]                                   # scalar int32
-    kp = kpos_ref[...]                                 # (1, bkv)
+    qp = qpos_ref[pl.program_id(0)]                    # scalar int32
+    kp = kpos_ref[0]                                   # (1, bkv)
     # one mask covers causality, empty (sentinel-pos) slots and the ring
     # window; padded cache tails carry the sentinel so they fail kp <= qp
     ok = (kp <= qp) & (qp - kp < window)
-    s = jnp.where(ok, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    # explicit mask on p: an all-masked block would otherwise exp(0)=1
-    # while m is still NEG_INF
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * corr + p @ v_ref[0, :, 0].astype(jnp.float32)
+    for g in range(n_kv):                              # static: KV heads
+        q = q_ref[0, g].astype(jnp.float32) * scale    # (rep, dh)
+        k = k_ref[0, :, g].astype(jnp.float32)         # (bkv, dh)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(ok, s, NEG_INF)                  # (rep, bkv)
+        m_prev = m_ref[g]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        # explicit mask on p: an all-masked block would otherwise exp(0)=1
+        # while m is still NEG_INF
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[g] = l_ref[g] * corr + p.sum(-1, keepdims=True)
+        m_ref[g] = m_new
+        acc_ref[g] = acc_ref[g] * corr + jnp.dot(
+            p, v_ref[0, :, g].astype(jnp.float32),
+            preferred_element_type=jnp.float32)
 
     @pl.when(ki == nkv - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q: Array, k: Array, v: Array, q_pos: Array,
@@ -83,8 +89,9 @@ def decode_attention_pallas(q: Array, k: Array, v: Array, q_pos: Array,
     H = KV * rep, with query head h attending to KV head h // rep (the
     layout ``blockwise_attention`` and the serving cache pool share).
     ``window`` is the sliding-window width; 0 means un-windowed (masked
-    internally as window = C, the most a linear buffer can hold).
-    Returns (S, H, dh).
+    internally as window = C, the most a linear buffer can hold).  On
+    the chip ``bkv`` must be a multiple of 128 unless it covers the whole
+    cache.  Returns (S, H, dh).
     """
     s_slots, h, dh = q.shape
     c, n_kv = k.shape[1], k.shape[2]
@@ -100,25 +107,26 @@ def decode_attention_pallas(q: Array, k: Array, v: Array, q_pos: Array,
                          constant_values=jnp.iinfo(jnp.int32).max // 2)
     nkv = (c + pad) // bkv
     qg = q.reshape(s_slots, n_kv, rep, dh)
+    kv_spec = pl.BlockSpec((1, bkv, n_kv, dh), lambda b, j, qp: (b, j, 0, 0))
+    q_spec = pl.BlockSpec((1, n_kv, rep, dh), lambda b, j, qp: (b, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, window=window,
-                          nkv=nkv),
-        grid=(s_slots, n_kv, nkv),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, g, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, rep, dh), lambda b, g, j: (b, g, 0, 0)),
-            pl.BlockSpec((1, bkv, 1, dh), lambda b, g, j: (b, j, g, 0)),
-            pl.BlockSpec((1, bkv, 1, dh), lambda b, g, j: (b, j, g, 0)),
-            pl.BlockSpec((1, bkv), lambda b, g, j: (b, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, dh), lambda b, g, j: (b, g, 0, 0)),
+                          nkv=nkv, n_kv=n_kv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s_slots, nkv),
+            in_specs=[
+                q_spec, kv_spec, kv_spec,
+                pl.BlockSpec((1, 1, bkv), lambda b, j, qp: (b, 0, j)),
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((n_kv, rep, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rep, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rep, dh), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((s_slots, n_kv, rep, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, dh), jnp.float32),
-        ],
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), qg, k, v, kv_pos.astype(jnp.int32))
+    )(q_pos.astype(jnp.int32), qg, k, v,
+      kv_pos.astype(jnp.int32).reshape(s_slots, 1, c + pad))
     return out.reshape(s_slots, h, dh)

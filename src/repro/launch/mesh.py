@@ -21,16 +21,10 @@ def make_local_mesh():
 
 
 def make_abstract_mesh(shape, axes):
-    """Version-compat AbstractMesh constructor.
-
-    jax >= 0.5 takes ``AbstractMesh(axis_sizes, axis_names)``; jax <= 0.4.x
-    takes a single tuple of ``(name, size)`` pairs.  Abstract meshes carry
-    only shape/name information — exactly what the sharding rule engine and
-    its tests need without touching device state."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """AbstractMesh of the given axis sizes and names.  Abstract meshes
+    carry only shape/name information — exactly what the sharding rule
+    engine and its tests need without touching device state."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def batch_axes(mesh) -> tuple:

@@ -127,12 +127,13 @@ class ServeConfig:
 
 def _resolve_backend(name: str):
     """-> (backend, interpret) for decode_step_slots."""
+    if name not in ("auto", "reference", "pallas"):
+        raise ValueError(f"unknown attn_backend {name!r}; expected auto | "
+                         f"reference | pallas")
     on_tpu = jax.default_backend() == "tpu"
     if name == "auto":
-        return ("pallas", False) if on_tpu else ("reference", False)
-    if name == "pallas":
-        return "pallas", not on_tpu
-    return "reference", False
+        name = "pallas" if on_tpu else "reference"
+    return name, name == "pallas" and not on_tpu
 
 
 class ServeEngine:
@@ -167,7 +168,10 @@ class ServeEngine:
         self.cfg = cfg
         self.scfg = scfg
         self.rt = rt or T.Runtime()
-        self._backend, self._interpret = _resolve_backend(scfg.attn_backend)
+        # public, so a chip check can see a silent fallback to the
+        # reference or to interpret mode
+        self.attn_backend, self.attn_interpret = _resolve_backend(
+            scfg.attn_backend)
         self.state = self._init_state()
         self._block_fns: Dict[Optional[F.FaultPlan], callable] = {}
         self._admit = jax.jit(self._admit_impl, donate_argnums=(1,))
@@ -257,8 +261,8 @@ class ServeEngine:
                 running = running & ~frozen
             logits, cache = T.decode_step_slots(
                 params, st["cache"], {"tokens": st["last_tok"]}, self.cfg,
-                self.rt, step_mask=running, attn_backend=self._backend,
-                attn_interpret=self._interpret)
+                self.rt, step_mask=running, attn_backend=self.attn_backend,
+                attn_interpret=self.attn_interpret)
             lg = F.poison_logits(plan, st["t"], logits[:, 0, :])
             key, sub = jax.random.split(st["key"])
             tok = self._sample(lg, sub)
