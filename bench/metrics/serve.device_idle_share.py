@@ -1,0 +1,6 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 - union of operation intervals / window), in percent."""
+
+
+def read(run):
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
