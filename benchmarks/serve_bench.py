@@ -318,7 +318,8 @@ def bench_chaos(name, cfg, *, n_slots, block_steps, cache_len, n_requests,
         recs = eng2.resume_serve(
             fault_plan=dataclasses.replace(plan, crash_after_block=-1))
         resumed = True
-        stats = {k: eng.stats[k] + eng2.stats[k] for k in eng.stats}
+        stats = {k: eng.stats[k] + eng2.stats[k] for k in eng.stats
+                 if k != "last_serve"}
     wall = time.perf_counter() - t0
     counts, ok = _accounting(recs, n_requests)
     prefix_ok = all(

@@ -127,6 +127,7 @@ def decode_attention_pallas(q: Array, k: Array, v: Array, q_pos: Array,
             ]),
         out_shape=jax.ShapeDtypeStruct((s_slots, n_kv, rep, dh), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(q_pos.astype(jnp.int32), qg, k, v,
       kv_pos.astype(jnp.int32).reshape(s_slots, 1, c + pad))
     return out.reshape(s_slots, h, dh)

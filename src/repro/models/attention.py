@@ -297,9 +297,10 @@ def gqa_decode_slots(p: dict, x: Array, cache: dict, cfg: ModelConfig, *,
     slot = (lens % cache_len) if window > 0 \
         else jnp.minimum(lens, cache_len - 1)
     rows = jnp.arange(b, dtype=jnp.int32)
-    k_cache = cache["k"].at[rows, slot].set(k[:, 0])
-    v_cache = cache["v"].at[rows, slot].set(v[:, 0])
-    pos_cache = cache["pos"].at[rows, slot].set(lens)
+    with jax.named_scope("kv_write"):
+        k_cache = cache["k"].at[rows, slot].set(k[:, 0])
+        v_cache = cache["v"].at[rows, slot].set(v[:, 0])
+        pos_cache = cache["pos"].at[rows, slot].set(lens)
     if backend == "pallas":
         from repro.kernels.decode_attention import decode_attention_pallas
         out = decode_attention_pallas(q[:, 0], k_cache, v_cache, lens,
@@ -475,8 +476,9 @@ def mla_decode_slots(p: dict, x: Array, cache: dict, cfg: ModelConfig,
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
     c_new, kr_new = _mla_ckv(p, x, cfg, positions)
     rows = jnp.arange(b, dtype=jnp.int32)
-    c_cache = cache["c_kv"].at[rows, lens].set(c_new[:, 0])
-    kr_cache = cache["k_rope"].at[rows, lens].set(kr_new[:, 0])
+    with jax.named_scope("kv_write"):
+        c_cache = cache["c_kv"].at[rows, lens].set(c_new[:, 0])
+        kr_cache = cache["k_rope"].at[rows, lens].set(kr_new[:, 0])
 
     w_ukv = p["w_ukv"]["w"].reshape(m.kv_lora_rank, h,
                                     m.nope_head_dim + m.v_head_dim)

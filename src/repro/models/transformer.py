@@ -797,42 +797,52 @@ def decode_step_slots(params: dict, cache: dict, batch: dict,
             else:
                 bp, kc, vc, pc = layer
                 lc = {"k": kc, "v": vc, "pos": pc, "lens": lens}
-            a = rms_norm(h, bp["ln1"]["scale"], cfg.norm_eps)
-            if is_mla:
-                a, nc = attn.mla_decode_slots(bp["attn"], a, lc, cfg, rt=rt)
-                out_c = (nc["c_kv"], nc["k_rope"])
-            else:
-                a, nc = attn.gqa_decode_slots(bp["attn"], a, lc, cfg,
-                                              kind=kind, window=window,
-                                              rt=rt, **akw)
-                out_c = (nc["k"], nc["v"], nc["pos"])
+            with jax.named_scope("attention"):
+                a = rms_norm(h, bp["ln1"]["scale"], cfg.norm_eps)
+                if is_mla:
+                    a, nc = attn.mla_decode_slots(bp["attn"], a, lc, cfg,
+                                                  rt=rt)
+                    out_c = (nc["c_kv"], nc["k_rope"])
+                else:
+                    a, nc = attn.gqa_decode_slots(bp["attn"], a, lc, cfg,
+                                                  kind=kind, window=window,
+                                                  rt=rt, **akw)
+                    out_c = (nc["k"], nc["v"], nc["pos"])
             h = h + a
-            m = rms_norm(h, bp["ln2"]["scale"], cfg.norm_eps)
-            if "moe" in bp:
-                y, _ = moe_mod.moe_ffn(bp["moe"], m, cfg, mesh=rt.mesh,
-                                       ep_axis=rt.ep_axis,
-                                       batch_axes=rt.batch_axes)
-            else:
-                y = swiglu(bp["mlp"], m)
+            with jax.named_scope("mlp"):
+                m = rms_norm(h, bp["ln2"]["scale"], cfg.norm_eps)
+                if "moe" in bp:
+                    y, _ = moe_mod.moe_ffn(bp["moe"], m, cfg, mesh=rt.mesh,
+                                           ep_axis=rt.ep_axis,
+                                           batch_axes=rt.batch_axes)
+                else:
+                    y = swiglu(bp["mlp"], m)
             return h + y, out_c
 
-        if is_mla:
-            xs = (params["blocks"], cache["c_kv"], cache["k_rope"])
-            x, (nck, nkr) = jax.lax.scan(body, x, xs)
-            new_cache = dict(cache, c_kv=nck, k_rope=nkr)
-        else:
-            xs = (params["blocks"], cache["k"], cache["v"], cache["pos"])
-            x, (nk, nv, np_) = jax.lax.scan(body, x, xs)
-            new_cache = dict(cache, k=nk, v=nv, pos=np_)
+        # The scan itself reads each layer's slice of the pool (xs) and
+        # writes the updated slice back (ys), so those reads and writes
+        # carry this scope, not one of their own: reading the slice in
+        # the body instead changes the compiled block.
+        with jax.named_scope("layers"):
+            if is_mla:
+                xs = (params["blocks"], cache["c_kv"], cache["k_rope"])
+                x, (nck, nkr) = jax.lax.scan(body, x, xs)
+                new_cache = dict(cache, c_kv=nck, k_rope=nkr)
+            else:
+                xs = (params["blocks"], cache["k"], cache["v"],
+                      cache["pos"])
+                x, (nk, nv, np_) = jax.lax.scan(body, x, xs)
+                new_cache = dict(cache, k=nk, v=nv, pos=np_)
 
     new_lens = lens + 1 if step_mask is None \
         else jnp.where(step_mask, lens + 1, lens)
     new_cache["len"] = new_lens
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].T.astype(x.dtype)
-    else:
-        logits = linear(x, params["lm_head"])
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"].T.astype(x.dtype)
+        else:
+            logits = linear(x, params["lm_head"])
     return logits, new_cache
 
 

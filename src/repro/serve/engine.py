@@ -61,10 +61,12 @@ per token, batches run head-of-line until every member finishes.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import gc
 import time
 from dataclasses import dataclass
-from functools import partial
+from functools import partial, wraps
 from typing import Dict, List, Optional
 
 import jax
@@ -77,6 +79,7 @@ from repro.models import transformer as T
 from repro.serve import faults as F
 from repro.serve.pool import init_pool_cache, scatter_slot
 from repro.serve.scheduler import FifoScheduler, Request, RequestRecord
+from repro.tracing import Span
 
 Array = jax.Array
 
@@ -136,6 +139,108 @@ def _resolve_backend(name: str):
     return name, name == "pallas" and not on_tpu
 
 
+BlockRecord = collections.namedtuple("BlockRecord", (
+    "block", "t_s", "period_ns", "admit_ns", "dispatch_ns", "wait_ns",
+    "bookkeep_ns", "idle_ns", "cpu_ns", "gc_ns", "live_slots", "admits"))
+MAX_BLOCK_RECORDS = 65536      # about nine hours of 0.5 s blocks
+
+
+class BlockLog:
+    """The per-block record of one ``serve()`` / ``resume_serve()`` call,
+    which the engine keeps as ``stats["last_serve"]`` (``record``):
+
+    - ``blocks``: a ``BlockRecord`` per decode block, the last
+      ``MAX_BLOCK_RECORDS`` of them: ``block`` (its index in the call),
+      ``t_s`` (its dispatch, seconds since the call started),
+      ``period_ns`` (to the next dispatch, or to the end of the call for
+      the last block), the wall ns within that period of the spans
+      ``serve.admit``, ``serve.block.dispatch``, ``serve.block.wait``,
+      ``serve.block.bookkeep`` and ``serve.idle``, ``cpu_ns`` (the
+      serving thread's CPU time over the period outside ``wait`` and
+      ``idle``, as fine as ``time.thread_time_ns`` steps), ``gc_ns`` (time in garbage collection), ``live_slots``
+      and ``admits`` (admissions in the period);
+    - ``lead``: the same for the part of the call before the first
+      dispatch (``block`` -1);
+    - ``totals``: ``blocks`` and each summed field over the whole call.
+
+    The phases are disjoint, so they add up to at most the period; the
+    rest is host time outside the spans (the scheduler's calls)."""
+
+    SUMMED = BlockRecord._fields[2:]
+
+    def __init__(self):
+        self.record = {"blocks": collections.deque(maxlen=MAX_BLOCK_RECORDS),
+                       "lead": None,
+                       "totals": dict.fromkeys(("blocks",) + self.SUMMED, 0)}
+        self._t0 = self._wall0 = time.perf_counter_ns()
+        self._cpu0 = time.thread_time_ns()
+        self._block, self._t_s, self._gc0 = -1, 0.0, 0
+        self._open = dict.fromkeys(self.SUMMED, 0)
+        gc.callbacks.append(self._gc)
+
+    def _gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc0 = time.perf_counter_ns()
+        else:
+            self._open["gc_ns"] += time.perf_counter_ns() - self._gc0
+
+    def add(self, field: str, span: Span, on_cpu: bool = True) -> None:
+        """Count ``span``'s wall time under ``field``; a span that is not
+        the host's own work (``on_cpu=False``) leaves ``cpu_ns`` too."""
+        self._open[field] += span.wall_ns
+        if not on_cpu:
+            self._open["cpu_ns"] -= span.cpu_ns
+
+    def admitted(self, span: Span, waited: Optional[Span]) -> None:
+        self._open["admits"] += 1
+        self._open["admit_ns"] += span.wall_ns
+        if waited is not None:              # the first-token wait
+            self._open["admit_ns"] -= waited.wall_ns
+            self.add("wait_ns", waited, on_cpu=False)
+
+    def _close(self):
+        # the period's CPU reads lie inside its wall reads, so its CPU
+        # time exceeds its wall time by no more than the CPU clock's step
+        cpu = time.thread_time_ns()
+        wall = time.perf_counter_ns()
+        rec = BlockRecord(self._block, self._t_s, **dict(
+            self._open, period_ns=wall - self._wall0,
+            cpu_ns=self._open["cpu_ns"] + cpu - self._cpu0))
+        if rec.block < 0:
+            self.record["lead"] = rec
+        else:
+            self.record["blocks"].append(rec)
+        totals = self.record["totals"]
+        totals["blocks"] = self._block + 1
+        for k in self.SUMMED:
+            totals[k] += getattr(rec, k)
+        return wall, time.thread_time_ns()
+
+    def dispatch(self, live_slots: int) -> int:
+        """A decode block is dispatched: close the open record and open
+        the block's.  -> the block's index in the call."""
+        self._wall0, self._cpu0 = self._close()
+        self._block += 1
+        self._t_s = (self._wall0 - self._t0) * 1e-9
+        self._open = dict.fromkeys(self.SUMMED, 0)
+        self._open["live_slots"] = live_slots
+        return self._block
+
+    def close(self) -> None:
+        self._close()
+        gc.callbacks.remove(self._gc)
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``, which ``jax.jit`` gives its program
+    (``jit_<name>``) and a profiler trace shows."""
+    @wraps(fn)
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 class ServeEngine:
     """Continuous-batching engine for one model family.
 
@@ -148,8 +253,13 @@ class ServeEngine:
                                              # scheduler.TERMINAL_STATES)
 
     ``eng.stats`` counts compiled-call dispatches and blocking host
-    readbacks by kind; the benchmark derives dispatches-per-token and
-    host-syncs-per-token from it instead of asserting constants.
+    readbacks by kind over the engine's life; the benchmark derives
+    dispatches-per-token and host-syncs-per-token from it instead of
+    asserting constants.  ``stats["last_serve"]`` holds the per-block
+    record of the latest ``serve()`` / ``resume_serve()`` call
+    (``BlockLog``), timed by the ``serve.*`` host spans that a profiler
+    session also records.  The decode block and the admission run as
+    the programs ``jit_serve_decode_block`` and ``jit_serve_admit``.
     """
 
     def __init__(self, params, cfg: ModelConfig, scfg: ServeConfig,
@@ -174,13 +284,15 @@ class ServeEngine:
             scfg.attn_backend)
         self.state = self._init_state()
         self._block_fns: Dict[Optional[F.FaultPlan], callable] = {}
-        self._admit = jax.jit(self._admit_impl, donate_argnums=(1,))
+        self._admit = jax.jit(_named(self._admit_impl, "serve_admit"),
+                              donate_argnums=(1,))
         self._resume_sched: Optional[FifoScheduler] = None
         self._blocks_done = 0
         self.stats = {"block_dispatches": 0, "block_syncs": 0,
                       "block_tokens": 0, "admit_dispatches": 0,
                       "request_reads": 0, "faults_detected": 0,
-                      "stalls_detected": 0, "snapshot_writes": 0}
+                      "stalls_detected": 0, "snapshot_writes": 0,
+                      "last_serve": None}
 
     # ------------------------------------------------------------------
     def _init_state(self) -> dict:
@@ -301,12 +413,15 @@ class ServeEngine:
         key = None if plan is None or plan.device_silent else plan
         if key not in self._block_fns:
             self._block_fns[key] = jax.jit(
-                partial(self._block_impl, key), donate_argnums=(1,))
+                _named(partial(self._block_impl, key), "serve_decode_block"),
+                donate_argnums=(1,))
         return self._block_fns[key]
 
     # ------------------------------------------------------------------
     def _admit_request(self, req: Request, rec: RequestRecord,
-                       sync_ttft: bool, now) -> None:
+                       sync_ttft: bool, now) -> Optional[Span]:
+        """Dispatch one admission; -> the first-token wait's span with
+        ``sync_ttft``, else None."""
         scfg = self.scfg
         max_new = req.max_new if req.max_new is not None \
             else scfg.max_new_tokens
@@ -325,10 +440,13 @@ class ServeEngine:
         self.stats["admit_dispatches"] += 1
         first = self.state["last_tok"][rec.slot, 0]
         rec.tokens.append(first)           # device scalar; resolved lazily
-        if sync_ttft:
+        if not sync_ttft:
+            return None
+        with Span("serve.block.wait", rid=req.rid) as waited:
             first.block_until_ready()
-            self.stats["request_reads"] += 1
-            rec.first_token_s = now()
+        self.stats["request_reads"] += 1
+        rec.first_token_s = now()
+        return waited
 
     def serve(self, requests: List[Request], *, sync_ttft: bool = False,
               fault_plan: Optional[F.FaultPlan] = None,
@@ -386,95 +504,123 @@ class ServeEngine:
         block = self._get_block(fault_plan)
         self._sched = sched
         stall = [0] * scfg.n_slots
+        log = BlockLog()
+        self.stats["last_serve"] = log.record
         t0 = time.perf_counter()
 
         def now():
             return time.perf_counter() - t0
 
-        while not sched.done:
-            sched.shed_expired(now())
-            while sched.admissible(now()):
-                req, slot = sched.pop(now())
-                stall[slot] = 0
-                self._admit_request(req, sched.records[req.rid],
-                                    sync_ttft, now)
-                # a request that stops at its first token never decodes
-                if (req.max_new or scfg.max_new_tokens) <= 1:
-                    rec = sched.records[req.rid]
-                    if rec.first_token_s is None:
-                        rec.first_token_s = now()
-                    sched.release(slot, now())
-            busy = [s for s, rid in enumerate(sched.slot_rid)
-                    if rid is not None]
-            if not busy:
-                nr = sched.next_ready()
-                if nr is None:
-                    break
-                wait = nr - now()
-                if wait > 0:
-                    time.sleep(wait)
-                continue
-            if (fault_plan is not None and fault_plan.delay_s > 0
-                    and self._blocks_done in fault_plan.delay_blocks):
-                time.sleep(fault_plan.delay_s)
-            # watchdog, part 1: deadline-expired slots are cancelled ON
-            # DEVICE by the block dispatch itself (no extra dispatch)
-            cancel = np.zeros((scfg.n_slots,), bool)
-            t_check = now()
-            for s in busy:
-                if t_check > sched.abs_deadline(sched.slot_rid[s]):
-                    cancel[s] = True
-            self.state, toks, emitted = block(self.params, self.state,
-                                              jnp.asarray(cancel))
-            self.stats["block_dispatches"] += 1
-            # ONE readback per block: tokens, emission mask, stop and
-            # fault flags
-            toks_h, emitted_h, stopped_h, fault_h = jax.device_get(
-                (toks, emitted, self.state["stopped"],
-                 self.state["fault"]))
-            self.stats["block_syncs"] += 1
-            t_block = now()
-            for s in busy:
-                rec = sched.records[sched.slot_rid[s]]
-                if cancel[s]:
-                    sched.release(s, t_block, state="timed_out")
+        try:
+            while not sched.done:
+                sched.shed_expired(now())
+                while sched.admissible(now()):
+                    req, slot = sched.pop(now())
+                    stall[slot] = 0
+                    with Span("serve.admit", rid=req.rid, slot=slot,
+                              prompt_len=len(req.tokens)) as sp:
+                        waited = self._admit_request(
+                            req, sched.records[req.rid], sync_ttft, now)
+                    log.admitted(sp, waited)
+                    # a request that stops at its first token never decodes
+                    if (req.max_new or scfg.max_new_tokens) <= 1:
+                        rec = sched.records[req.rid]
+                        if rec.first_token_s is None:
+                            rec.first_token_s = now()
+                        sched.release(slot, now())
+                busy = [s for s, rid in enumerate(sched.slot_rid)
+                        if rid is not None]
+                if not busy:
+                    nr = sched.next_ready()
+                    if nr is None:
+                        break
+                    wait = nr - now()
+                    if wait > 0:
+                        with Span("serve.idle") as sp:
+                            time.sleep(wait)
+                        log.add("idle_ns", sp, on_cpu=False)
                     continue
-                new = toks_h[emitted_h[:, s], s]
-                rec.tokens.extend(int(t) for t in new)
-                self.stats["block_tokens"] += int(emitted_h[:, s].sum())
-                if rec.first_token_s is None and len(rec.tokens) > 0:
-                    rec.first_token_s = t_block
-                if fault_h[s]:
-                    rec.faults += 1
-                    self.stats["faults_detected"] += 1
-                    self._retry_or_fail(sched, s, t_block)
-                elif stopped_h[s]:
-                    sched.release(s, t_block)
-                elif scfg.stall_blocks > 0 and not emitted_h[:, s].any():
-                    # watchdog, part 2: a live slot that emitted nothing
-                    stall[s] += 1
-                    if stall[s] >= scfg.stall_blocks:
-                        stall[s] = 0
-                        self.stats["stalls_detected"] += 1
-                        self._retry_or_fail(sched, s, t_block)
-                else:
-                    stall[s] = 0
-            self._blocks_done += 1
-            if (snapshot_path and snapshot_every_blocks > 0
-                    and self._blocks_done % snapshot_every_blocks == 0):
-                self.snapshot(snapshot_path, sched)
-            if (fault_plan is not None
-                    and fault_plan.crash_after_block >= 0
-                    and self._blocks_done - 1
-                    == fault_plan.crash_after_block):
-                raise F.SimulatedCrash(
-                    f"fault plan killed the engine after block "
-                    f"{fault_plan.crash_after_block}"
-                    + (f"; resume from {snapshot_path!r}"
-                       if snapshot_path else ""))
+                if (fault_plan is not None and fault_plan.delay_s > 0
+                        and self._blocks_done in fault_plan.delay_blocks):
+                    time.sleep(fault_plan.delay_s)
+                # watchdog, part 1: deadline-expired slots are cancelled ON
+                # DEVICE by the block dispatch itself (no extra dispatch)
+                cancel = np.zeros((scfg.n_slots,), bool)
+                t_check = now()
+                for s in busy:
+                    if t_check > sched.abs_deadline(sched.slot_rid[s]):
+                        cancel[s] = True
+                n = log.dispatch(len(busy))
+                with Span("serve.block", block=n, live_slots=len(busy)):
+                    with Span("serve.block.dispatch") as sp:
+                        self.state, toks, emitted = block(
+                            self.params, self.state, jnp.asarray(cancel))
+                    log.add("dispatch_ns", sp)
+                    self.stats["block_dispatches"] += 1
+                    # ONE readback per block: tokens, emission mask, stop
+                    # and fault flags
+                    with Span("serve.block.wait") as sp:
+                        readback = jax.device_get(
+                            (toks, emitted, self.state["stopped"],
+                             self.state["fault"]))
+                    log.add("wait_ns", sp, on_cpu=False)
+                    self.stats["block_syncs"] += 1
+                    with Span("serve.block.bookkeep") as sp:
+                        self._bookkeep(sched, busy, cancel, readback, stall,
+                                       now())
+                        self._blocks_done += 1
+                        if (snapshot_path and snapshot_every_blocks > 0
+                                and self._blocks_done
+                                % snapshot_every_blocks == 0):
+                            self.snapshot(snapshot_path, sched)
+                    log.add("bookkeep_ns", sp)
+                if (fault_plan is not None
+                        and fault_plan.crash_after_block >= 0
+                        and self._blocks_done - 1
+                        == fault_plan.crash_after_block):
+                    raise F.SimulatedCrash(
+                        f"fault plan killed the engine after block "
+                        f"{fault_plan.crash_after_block}"
+                        + (f"; resume from {snapshot_path!r}"
+                           if snapshot_path else ""))
+        finally:
+            log.close()
         for rec in sched.records.values():      # resolve lazy first tokens
             rec.tokens = [int(t) for t in rec.tokens]
         return sched.records
+
+    def _bookkeep(self, sched: FifoScheduler, busy: List[int],
+                  cancel: np.ndarray, readback, stall: List[int],
+                  t_block: float) -> None:
+        """A block's host work per live slot: append its tokens, release
+        finished and cancelled slots, retry faulted ones, and run the
+        stall watchdog."""
+        toks_h, emitted_h, stopped_h, fault_h = readback
+        for s in busy:
+            rec = sched.records[sched.slot_rid[s]]
+            if cancel[s]:
+                sched.release(s, t_block, state="timed_out")
+                continue
+            new = toks_h[emitted_h[:, s], s]
+            rec.tokens.extend(int(t) for t in new)
+            self.stats["block_tokens"] += int(emitted_h[:, s].sum())
+            if rec.first_token_s is None and len(rec.tokens) > 0:
+                rec.first_token_s = t_block
+            if fault_h[s]:
+                rec.faults += 1
+                self.stats["faults_detected"] += 1
+                self._retry_or_fail(sched, s, t_block)
+            elif stopped_h[s]:
+                sched.release(s, t_block)
+            elif self.scfg.stall_blocks > 0 and not emitted_h[:, s].any():
+                # watchdog, part 2: a live slot that emitted nothing
+                stall[s] += 1
+                if stall[s] >= self.scfg.stall_blocks:
+                    stall[s] = 0
+                    self.stats["stalls_detected"] += 1
+                    self._retry_or_fail(sched, s, t_block)
+            else:
+                stall[s] = 0
 
     def _retry_or_fail(self, sched: FifoScheduler, slot: int,
                        now_s: float) -> None:
