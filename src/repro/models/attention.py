@@ -269,7 +269,8 @@ def gqa_decode_slots(p: dict, x: Array, cache: dict, cfg: ModelConfig, *,
                      kind: str = "causal", window: int = 0,
                      n_heads=None, n_kv=None, rt=None,
                      backend: str = "reference",
-                     interpret: bool = False) -> Tuple[Array, dict]:
+                     interpret: bool = False,
+                     layer: Optional[Array] = None) -> Tuple[Array, dict]:
     """One-token decode with PER-SLOT positions (the serving cache pool).
 
     Unlike ``gqa_decode`` (one scalar ``len`` for the whole batch), every
@@ -283,11 +284,16 @@ def gqa_decode_slots(p: dict, x: Array, cache: dict, cfg: ModelConfig, *,
     ``backend='pallas'`` routes the attention contraction to
     ``kernels.decode_attention`` (interpret mode off-TPU); the default is
     the blockwise jnp oracle.
+
+    With ``layer`` (a scalar, may be traced), ``k``/``v``/``pos`` are the
+    model's stacked (L, S, C, KV, dh) / (L, S, C) pool: the new rows are
+    written at ``[layer, s, slot]`` and the whole updated pool comes back,
+    so a layer scan can carry it in place.
     """
     h = n_heads or cfg.n_heads
     kvh = n_kv or cfg.n_kv_heads
     b = x.shape[0]
-    cache_len = cache["k"].shape[1]
+    cache_len = cache["k"].shape[-3]
     lens = cache["lens"]                                  # (S,) int32
     positions = lens[:, None]                             # (S, 1)
     q, k, v = _qkv(p, x, x, cfg, h, kvh)
@@ -297,20 +303,23 @@ def gqa_decode_slots(p: dict, x: Array, cache: dict, cfg: ModelConfig, *,
     slot = (lens % cache_len) if window > 0 \
         else jnp.minimum(lens, cache_len - 1)
     rows = jnp.arange(b, dtype=jnp.int32)
+    at = (rows, slot) if layer is None else (layer, rows, slot)
     with jax.named_scope("kv_write"):
-        k_cache = cache["k"].at[rows, slot].set(k[:, 0])
-        v_cache = cache["v"].at[rows, slot].set(v[:, 0])
-        pos_cache = cache["pos"].at[rows, slot].set(lens)
+        k_cache = cache["k"].at[at].set(k[:, 0])
+        v_cache = cache["v"].at[at].set(v[:, 0])
+        pos_cache = cache["pos"].at[at].set(lens)
     if backend == "pallas":
         from repro.kernels.decode_attention import decode_attention_pallas
         out = decode_attention_pallas(q[:, 0], k_cache, v_cache, lens,
-                                      pos_cache, window=window,
+                                      pos_cache, window=window, layer=layer,
                                       interpret=interpret)[:, None]
     else:
-        out = blockwise_attention(q, k_cache, v_cache, kind=kind,
+        kr, vr, pr = (k_cache, v_cache, pos_cache) if layer is None else \
+            (k_cache[layer], v_cache[layer], pos_cache[layer])
+        out = blockwise_attention(q, kr, vr, kind=kind,
                                   window=window or cache_len,
                                   q_positions=positions,
-                                  kv_positions=pos_cache, rt=rt)
+                                  kv_positions=pr, rt=rt)
     new_cache = {"k": k_cache, "v": v_cache, "pos": pos_cache,
                  "lens": lens + 1}
     o = linear(out.reshape(b, 1, h * cfg.head_dim), p["wo"])

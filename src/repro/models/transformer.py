@@ -788,26 +788,10 @@ def decode_step_slots(params: dict, cache: dict, batch: dict,
             new_tail.append(nc)
         new_cache = dict(cache, groups=new_groups, tail=new_tail)
     else:  # dense / vlm / moe
-        is_mla = cfg.mla is not None
-
-        def body(h, layer):
-            if is_mla:
-                bp, ck, kr = layer
-                lc = {"c_kv": ck, "k_rope": kr, "lens": lens}
-            else:
-                bp, kc, vc, pc = layer
-                lc = {"k": kc, "v": vc, "pos": pc, "lens": lens}
+        def block(h, bp, attend):
             with jax.named_scope("attention"):
                 a = rms_norm(h, bp["ln1"]["scale"], cfg.norm_eps)
-                if is_mla:
-                    a, nc = attn.mla_decode_slots(bp["attn"], a, lc, cfg,
-                                                  rt=rt)
-                    out_c = (nc["c_kv"], nc["k_rope"])
-                else:
-                    a, nc = attn.gqa_decode_slots(bp["attn"], a, lc, cfg,
-                                                  kind=kind, window=window,
-                                                  rt=rt, **akw)
-                    out_c = (nc["k"], nc["v"], nc["pos"])
+                a, nc = attend(bp["attn"], a)
             h = h + a
             with jax.named_scope("mlp"):
                 m = rms_norm(h, bp["ln2"]["scale"], cfg.norm_eps)
@@ -817,21 +801,36 @@ def decode_step_slots(params: dict, cache: dict, batch: dict,
                                            batch_axes=rt.batch_axes)
                 else:
                     y = swiglu(bp["mlp"], m)
-            return h + y, out_c
+            return h + y, nc
 
-        # The scan itself reads each layer's slice of the pool (xs) and
-        # writes the updated slice back (ys), so those reads and writes
-        # carry this scope, not one of their own: reading the slice in
-        # the body instead changes the compiled block.
         with jax.named_scope("layers"):
-            if is_mla:
+            if cfg.mla is not None:
+                def body(h, layer):
+                    bp, ck, kr = layer
+                    lc = {"c_kv": ck, "k_rope": kr, "lens": lens}
+                    h, nc = block(h, bp, lambda p, a: attn.mla_decode_slots(
+                        p, a, lc, cfg, rt=rt))
+                    return h, (nc["c_kv"], nc["k_rope"])
                 xs = (params["blocks"], cache["c_kv"], cache["k_rope"])
                 x, (nck, nkr) = jax.lax.scan(body, x, xs)
                 new_cache = dict(cache, c_kv=nck, k_rope=nkr)
             else:
-                xs = (params["blocks"], cache["k"], cache["v"],
-                      cache["pos"])
-                x, (nk, nv, np_) = jax.lax.scan(body, x, xs)
+                # The stacked pool rides in the carry and is updated in
+                # place: layer i writes only its new token's row at
+                # [i, slot], and the decode kernel takes the whole pool
+                # and the layer index, because a slice of the carry fed
+                # to a custom call would be materialised each layer.
+                def body(carry, layer):
+                    h, kc, vc, pc = carry
+                    bp, i = layer
+                    lc = {"k": kc, "v": vc, "pos": pc, "lens": lens}
+                    h, nc = block(h, bp, lambda p, a: attn.gqa_decode_slots(
+                        p, a, lc, cfg, kind=kind, window=window, rt=rt,
+                        layer=i, **akw))
+                    return (h, nc["k"], nc["v"], nc["pos"]), None
+                carry = (x, cache["k"], cache["v"], cache["pos"])
+                xs = (params["blocks"], jnp.arange(cache["k"].shape[0]))
+                (x, nk, nv, np_), _ = jax.lax.scan(body, carry, xs)
                 new_cache = dict(cache, k=nk, v=nv, pos=np_)
 
     new_lens = lens + 1 if step_mask is None \

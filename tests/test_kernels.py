@@ -178,6 +178,28 @@ def test_decode_attention_matches_blockwise_oracle():
                                np.asarray(want[:, 0]), atol=1e-5)
 
 
+@pytest.mark.parametrize("window", [0, 24], ids=["linear", "ring"])
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+def test_decode_attention_stacked_layer(layer, window):
+    """With a layer index the kernel reads that layer of the stacked
+    (L, S, C, KV, dh) pool in place and equals the single-layer call on
+    the layer's slice.  16 slots: two 8-slot groups of positions."""
+    n_layers, s_slots, c, n_kv, rep, dh = 5, 16, 24, 2, 2, 32
+    top = 100 if window else c
+    lens = np.random.default_rng(3).integers(1, top + 1, s_slots)
+    # every layer its own K/V and positions, so reading the wrong one shows
+    pools = [_pool(40 + 3 * i, s_slots, c, n_kv, rep, dh,
+                   np.maximum(lens - i, 1), window=window)
+             for i in range(n_layers)]
+    q, lens = pools[layer][0], jnp.asarray(lens, jnp.int32)
+    k, v, pos = (jnp.stack([p[j] for p in pools]) for j in (1, 2, 4))
+    got = decode_attention_pallas(q, k, v, lens, pos, window=window, bkv=8,
+                                  layer=jnp.asarray(layer), interpret=True)
+    want = decode_attention_pallas(q, k[layer], v[layer], lens, pos[layer],
+                                   window=window, bkv=8, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_decode_attention_bf16():
     s_slots, c, n_kv, rep, dh = 2, 32, 2, 2, 32
     q, k, v, lens, pos = _pool(29, s_slots, c, n_kv, rep, dh, [32, 11],

@@ -8,6 +8,7 @@ alone.  Dispatch structure (one compiled call + one readback per M-step
 block) is MEASURED from engine counters, not assumed.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,6 +164,26 @@ def test_pallas_decode_backend_matches_reference(tiny):
         recs = ServeEngine(params, cfg, scfg).serve(reqs)
         outs[backend] = {r.rid: recs[r.rid].tokens for r in reqs}
     assert outs["reference"] == outs["pallas"]
+
+
+def test_decode_block_keeps_pool_in_place(tiny):
+    """The layer scan carries the stacked K/V pool and the decode kernel
+    reads its layer in place: the compiled block holds no copy,
+    dynamic-slice or dynamic-update-slice of one layer's (S, C, KV, dh)
+    pool.  On the CPU the interpreted kernel and the carries add copies
+    of the whole pool that the chip's compiler drops, so the whole-pool
+    shape is checked in ``test_tpu_compile.py`` only."""
+    cfg, params = tiny
+    scfg = ServeConfig(n_slots=3, cache_len=64, block_steps=2,
+                       attn_backend="pallas")
+    eng = ServeEngine(params, cfg, scfg)
+    hlo = eng._get_block(None).lower(
+        eng.params, eng.state, jnp.zeros((scfg.n_slots,), bool)
+    ).compile().as_text()
+    layer = f"{scfg.n_slots},{scfg.cache_len},{cfg.n_kv_heads},{cfg.head_dim}"
+    moved = re.compile(r"= \w+\[(1,)*" + layer + r"\]\S* "
+                       r"(copy|dynamic-slice|dynamic-update-slice)\(")
+    assert not [ln for ln in hlo.splitlines() if moved.search(ln)]
 
 
 def test_scatter_gather_roundtrip(tiny):
